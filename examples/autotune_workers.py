@@ -4,6 +4,7 @@ per-decoder recommendation with the 5% practical-significance rule.
 
 Run:  PYTHONPATH=src python examples/autotune_workers.py
 """
+from repro.common.compile_cache import use_compile_cache
 from repro.data.autotune import autotune_workers
 from repro.data.loader import DataLoader, LoaderConfig
 from repro.jpeg.corpus import build_corpus
@@ -30,4 +31,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

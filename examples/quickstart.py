@@ -11,6 +11,7 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 """
 
 from repro.codecs import ExecContext, eligible, get_decoder, open_decoder
+from repro.common.compile_cache import use_compile_cache
 from repro.core import decision
 from repro.core.protocols import LoaderProtocol, SingleThreadProtocol
 from repro.jpeg.corpus import build_corpus
@@ -65,4 +66,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -27,6 +27,7 @@ import threading
 import urllib.request
 
 from repro.codecs import list_decoders
+from repro.common.compile_cache import use_compile_cache
 from repro.jpeg.corpus import build_corpus, zipf_indices
 from repro.service import DecodeService, ServiceConfig, ServiceOverloaded
 
@@ -97,4 +98,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -9,6 +9,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.common.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.models import model
 from repro.models.layers import ModelContext
@@ -47,4 +48,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -20,6 +20,7 @@ import time
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.common.compile_cache import use_compile_cache
 from repro.data.loader import DataLoader, LoaderConfig
 from repro.jpeg.corpus import (build_corpus, corpus_fingerprint,
                                load_corpus_shards, write_corpus_shards)
@@ -79,4 +80,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

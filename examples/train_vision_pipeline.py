@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.checkpoint import CheckpointManager
 from repro.codecs import get_decoder
+from repro.common.compile_cache import use_compile_cache
 from repro.data.autotune import autotune_workers
 from repro.data.loader import DataLoader, LoaderConfig
 from repro.jpeg.corpus import build_corpus
@@ -123,4 +124,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -1,8 +1,8 @@
 """Target-hardware constants for roofline analysis + host fingerprinting.
 
-The runtime here is CPU-only; TPU v5e is the *target*. These constants feed the
-three-term roofline (compute / memory / collective) derived from the compiled
-dry-run artifacts. Sources: public TPU v5e specs.
+The decode kernels run on a TPU v5e (and on the CPU backend in the tests).
+These constants feed the three-term roofline (compute / memory / collective)
+derived from the compiled dry-run artifacts. Sources: public TPU v5e specs.
 
 ``host_fingerprint()`` is the bench harness's machine identity: every emitted
 record set carries it so results are only ever compared across commits on the
@@ -104,8 +104,8 @@ def roofline_terms(
 ) -> dict:
     """Three-term roofline in seconds-per-step, per chip.
 
-    ``cost_analysis()`` on jax 0.8 reports per-device (post-SPMD-partitioning)
-    FLOPs and bytes, so all inputs here are per-chip quantities. The collective
+    ``cost_analysis()`` reports per-device (post-SPMD-partitioning) FLOPs
+    and bytes, so all inputs here are per-chip quantities. The collective
     term models each chip pushing its collective payload through its ICI links
     (all links usable in a 2D torus; we use a single-link bound as the
     conservative default, matching the prompt's ~50 GB/s/link figure).

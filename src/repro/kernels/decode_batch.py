@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: batched fused dequant + 8x8 IDCT + level shift + clamp.
+"""Pallas TPU kernel: batched fused dequant + 8x8 IDCT + level shift.
 
 Generalizes ``dequant_idct.py`` from one quant row to a whole micro-batch:
 the input is every block row of every batch member concatenated into
@@ -33,7 +33,7 @@ def _decode_batch_kernel(x_ref, qi_ref, qt_ref, m_ref, o_ref):
                 preferred_element_type=jnp.float32)       # (TILE_N, 64)
     deq = x_ref[...] * q
     pix = jnp.dot(deq, m_ref[...].T, preferred_element_type=jnp.float32)
-    o_ref[...] = jnp.clip(pix + 128.0, 0.0, 255.0)
+    o_ref[...] = pix + 128.0      # unclamped, as in dequant_idct.py
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -42,7 +42,7 @@ def decode_batch_pallas(x: jax.Array, qidx: jax.Array, qtab: jax.Array,
                         ) -> jax.Array:
     """x: [N, 64] f32 raw coefficient rows (N multiple of TILE_N);
     qidx: [N, 1] i32 per-row quant-table index; qtab: [T, 64] quant rows;
-    m: [64, 64] Kronecker IDCT matrix. -> [N, 64] clamped pixel rows."""
+    m: [64, 64] Kronecker IDCT matrix. -> [N, 64] level-shifted rows."""
     n = x.shape[0]
     t = qtab.shape[0]
     assert n % TILE_N == 0, n

@@ -1,7 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handles padding to tile multiples, dtype casts, and interpret-mode fallback
-(this runtime is CPU-only; on TPU the same calls lower through Mosaic).
+Handles padding to tile multiples and dtype casts. On a TPU the kernels
+lower through Mosaic; on the CPU backend, where the tests run, they run in
+Pallas interpret mode.
 """
 from __future__ import annotations
 
@@ -41,7 +42,7 @@ def idct8x8(x) -> jax.Array:
 
 
 def dequant_idct(x, q) -> jax.Array:
-    """[N, 64] raw coefficients + [64] quant row -> clamped pixel rows."""
+    """[N, 64] raw coefficients + [64] quant row -> level-shifted rows."""
     x = jnp.asarray(x, jnp.float32)
     q = jnp.asarray(q, jnp.float32).reshape(1, 64)
     xp, n = _pad_rows(x, DQ_TILE)
@@ -51,7 +52,7 @@ def dequant_idct(x, q) -> jax.Array:
 
 def decode_batch(x, qidx, qtables) -> jax.Array:
     """Batched fused dequant+IDCT: [N, 64] rows + [N] per-row table index
-    + [T, 64] quant tables -> [N, 64] clamped pixel rows (one launch for a
+    + [T, 64] quant tables -> [N, 64] level-shifted rows (one launch for a
     whole micro-batch; rows from different images interleave freely)."""
     x = jnp.asarray(x, jnp.float32)
     qidx = jnp.asarray(qidx, jnp.int32).reshape(-1, 1)

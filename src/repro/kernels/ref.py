@@ -17,14 +17,12 @@ def idct8x8(x: jax.Array) -> jax.Array:
 
 def dequant_idct(x: jax.Array, q: jax.Array) -> jax.Array:
     """x: [N, 64] raw coefficients; q: [64] quant table row."""
-    pix = (x * q[None, :]) @ jnp.asarray(IDCT64).T + 128.0
-    return jnp.clip(pix, 0.0, 255.0)
+    return (x * q[None, :]) @ jnp.asarray(IDCT64).T + 128.0
 
 
 def decode_batch(x: jax.Array, qidx: jax.Array, qtab: jax.Array) -> jax.Array:
     """x: [N, 64] raw rows; qidx: [N] i32 table index; qtab: [T, 64]."""
-    pix = (x * qtab[qidx]) @ jnp.asarray(IDCT64).T + 128.0
-    return jnp.clip(pix, 0.0, 255.0)
+    return (x * qtab[qidx]) @ jnp.asarray(IDCT64).T + 128.0
 
 
 def ycbcr2rgb(y: jax.Array, cb: jax.Array, cr: jax.Array):

@@ -20,23 +20,6 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-try:  # jax >= 0.5 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma; detect
-# from the signature rather than the import location (top-level shard_map
-# existed for some releases while the kwarg was still check_rep)
-import inspect
-_CHECK_KW = ("check_vma" if "check_vma"
-             in inspect.signature(_shard_map).parameters else "check_rep")
-
-
-def shard_map(*args, **kw):
-    if "check_vma" in kw:
-        kw[_CHECK_KW] = kw.pop("check_vma")
-    return _shard_map(*args, **kw)
 from jax.sharding import PartitionSpec as P
 
 Params = Dict[str, Any]
@@ -145,7 +128,7 @@ def routed_ep(x: jax.Array, p: Params, cfg, ctx) -> Tuple[jax.Array, jax.Array]:
         y = out_rows.reshape(T, k, d).sum(axis=1)
         return y.reshape(bl, sl, d), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(tok_spec, P(None, None), P(ctx.model_axis, None, None),
                   P(ctx.model_axis, None, None), P(ctx.model_axis, None, None)),
