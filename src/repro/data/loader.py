@@ -551,7 +551,11 @@ def prefetch_to_device(iterator, size: int = 2):
     def producer():
         try:
             for item in iterator:
-                if not _put(jax.device_put(item)):
+                # device_put is asynchronous: the span times the hand-off
+                # and staging, not the transfer's completion
+                with trace.span("prefetch.device_put"):
+                    item = jax.device_put(item)
+                if not _put(item):
                     return               # consumer abandoned the generator
         except BaseException as e:
             error.append(e)

@@ -198,7 +198,9 @@ def _one_of_batch(batch_fn) -> Callable[[bytes], np.ndarray]:
 # ------------------------------------------------------------ pallas family
 def _ycbcr_kernel(y, cb, cr) -> np.ndarray:
     from repro.kernels import ops
-    return np.asarray(ops.ycbcr2rgb(y, cb, cr))
+    # the colour round trip: upload three planes, kernel, copy back
+    with trace.span("jpeg.color"):
+        return np.asarray(ops.ycbcr2rgb(y, cb, cr))
 
 
 def _pallas_idct(data: bytes, strict: bool = False) -> np.ndarray:
@@ -249,8 +251,11 @@ def _pallas_transform_group(specs, coefs) -> List[np.ndarray]:
             qtabs.append(spec.qtables[c.tq].astype(np.float32).reshape(64))
             spans.append((len(r), by, bx))
             rows.append(r)
-    pix = np.asarray(ops.decode_batch(
-        np.concatenate(rows), np.concatenate(ridx), np.stack(qtabs)))
+    rows, ridx, qtabs = (np.concatenate(rows), np.concatenate(ridx),
+                         np.stack(qtabs))
+    # the device transform, launch to pixels on the host
+    with trace.span("jpeg.transform", batch=len(specs), rows=len(rows)):
+        pix = np.asarray(ops.decode_batch(rows, ridx, qtabs))
     imgs, pos, si = [], 0, 0
     for spec in specs:
         planes = []

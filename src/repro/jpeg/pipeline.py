@@ -213,11 +213,6 @@ def _transform_jit(coefs, qtables, *, n_comp, factors, h, w, separable):
 
 
 # -------------------------------------------------- batched transforms
-# Observability hook: incremented once per fused batched-transform launch.
-# The service test asserts a full micro-batch costs ONE launch, not B.
-TRANSFORM_BATCH_CALLS = 0
-
-
 def assemble_plane_batch_jnp(blocks):
     """[B, by, bx, 8, 8] -> [B, by*8, bx*8]."""
     b, by, bx = blocks.shape[:3]
@@ -307,12 +302,10 @@ def transform_batch(specs: Sequence[DecodeSpec],
     member: every stage is pointwise per image (the IDCT GEMM reduces
     over the fixed 64-wide axis), so batching only changes launch count.
     """
-    global TRANSFORM_BATCH_CALLS
     stacked, qstacked = batch_layout(specs, coefs)
     hmax = max(c.h for c in specs[0].components)
     vmax = max(c.v for c in specs[0].components)
     factors = tuple((hmax // c.h, vmax // c.v) for c in specs[0].components)
-    TRANSFORM_BATCH_CALLS += 1
     # one fused launch: dequant/IDCT/assemble are not separable stages
     # under jit, so the whole device transform is one span
     with trace.span("jpeg.transform", batch=len(specs)):

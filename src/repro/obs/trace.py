@@ -27,12 +27,23 @@ Design rules:
   inherit it naturally; worker *processes* receive a
   ``worker_config()`` through pool initargs and rebuild a shard-writing
   tracer on their side.
+* **Spans also land in JAX's profiler trace.** Where the process has
+  imported JAX, an enabled ``Tracer``'s spans each open a
+  ``jax.profiler.TraceAnnotation`` (see ``Tracer``). Under
+  ``jax.profiler.trace`` the decode pipeline's threads then appear in
+  the ``.xplane.pb`` on the ``/host:CPU`` plane, on the device trace's
+  clock; ``time.monotonic`` stays the ring buffer's clock. A tracer
+  rebuilt by ``init_worker``, and a process forked from one that had
+  loaded JAX, annotate nothing: only the process that holds the chip
+  can trace it. This module never loads JAX: it looks the class up
+  only once ``jax`` is in ``sys.modules``.
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -101,12 +112,39 @@ class NullTracer:
 NULL = NullTracer()
 
 
+# ------------------------------------------------- profiler annotations
+_annotation_cls = None       # jax.profiler.TraceAnnotation, once resolved
+_forked_from_jax = False     # a fork of a process that had loaded JAX
+
+
+def _after_fork_in_child() -> None:
+    global _forked_from_jax
+    _forked_from_jax = _forked_from_jax or "jax" in sys.modules
+
+
+os.register_at_fork(after_in_child=_after_fork_in_child)
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` where this process has imported
+    JAX and is not a fork of one that had; None otherwise."""
+    global _annotation_cls
+    if _forked_from_jax:
+        return None
+    if _annotation_cls is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
 # ------------------------------------------------------------------ spans
 class _Span:
     """One live complete-span ('X' phase): clock read on enter, event
-    emission on exit. ``set(**args)`` attaches arguments before close."""
+    emission on exit. ``set(**args)`` attaches arguments before close.
+    Where JAX is loaded, the span also opens a profiler annotation with
+    the arguments it has at open."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[dict]):
@@ -123,13 +161,20 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
+        cls = _trace_annotation() if self._tracer._annotates else None
+        self._ann = None
+        if cls is not None:
+            self._ann = cls(self._name, **(self._args or {}))
+            self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> bool:
         t0 = self._t0
-        self._tracer._emit(self._name, self._cat, "X", t0,
-                           time.monotonic() - t0, self._args)
+        dur = time.monotonic() - t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._tracer._emit(self._name, self._cat, "X", t0, dur, self._args)
         return False
 
 
@@ -141,12 +186,20 @@ class Tracer:
     events to ``<shard_dir>/trace-<pid>.jsonl`` and clears the buffer;
     with ``autoflush=N`` a flush triggers automatically once N events
     are pending (how pool workers survive ``Pool.terminate``).
+
+    Only ``span()`` reaches JAX's profiler: each span opens a
+    ``TraceAnnotation`` named as the span, with the arguments given at
+    open as its stats (arguments added later with ``set()`` stay in the
+    ring buffer). ``complete()`` events are recorded after the fact, and
+    ``instant()`` and ``counter()`` have no extent, so none of the three
+    is annotated.
     """
 
     enabled = True
 
     def __init__(self, *, maxlen: int = 1 << 16,
                  shard_dir: Optional[str] = None, autoflush: int = 0):
+        self._annotates = True       # init_worker clears it in pool workers
         self._buf: deque = deque(maxlen=maxlen)
         self._lock = threading.Lock()
         self._pid = os.getpid()
@@ -406,9 +459,12 @@ def flush() -> None:
 
 def init_worker(config: Optional[dict]) -> None:
     """Pool-worker side of ``worker_config()``: install a shard-writing
-    tracer in this process (no-op when the parent wasn't tracing)."""
+    tracer in this process (no-op when the parent wasn't tracing). It
+    opens no profiler annotations: a worker does not hold the chip."""
     if config:
-        set_tracer(Tracer(**config))
+        tracer = Tracer(**config)
+        tracer._annotates = False
+        set_tracer(tracer)
 
 
 # ------------------------------------------------------------- artifacts

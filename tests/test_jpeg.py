@@ -245,13 +245,14 @@ def test_decode_batch_isolates_bad_items(corpus):
 def test_decode_batch_one_transform_per_structure_group(corpus):
     """The whole point of bucketing: B same-structure images cost ONE
     fused transform launch, not B."""
-    from repro.jpeg import pipeline
+    from repro.obs import trace
     files = [encoder.encode_jpeg(_img(h=64, w=64, seed=10 + k),
                                  quality=85, subsampling="420")
              for k in range(4)]
-    before = pipeline.TRANSFORM_BATCH_CALLS
-    out = DECODE_PATHS["jnp-batch"].decode_batch(files)
-    assert pipeline.TRANSFORM_BATCH_CALLS == before + 1
+    with trace.use_tracer(trace.Tracer()) as tracer:
+        out = DECODE_PATHS["jnp-batch"].decode_batch(files)
+    launches = [e for e in tracer.events() if e["name"] == "jpeg.transform"]
+    assert len(launches) == 1 and launches[0]["args"] == {"batch": 4}
     assert all(not isinstance(r, BaseException) for r in out)
 
 

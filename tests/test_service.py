@@ -167,20 +167,22 @@ def test_serve_batch_makes_one_decode_batch_call(corpus):
 def test_service_batched_path_counts_one_transform_per_batch():
     """End-to-end through jnp-batch: 4 same-bucket images in a micro-batch
     cost exactly one fused transform invocation."""
-    from repro.jpeg import encoder, pipeline
+    from repro.jpeg import encoder
     from repro.jpeg.corpus import natural_image
+    from repro.obs import trace
     files = [encoder.encode_jpeg(
         natural_image(np.random.RandomState(20 + k), 64, 64),
         quality=85, subsampling="420") for k in range(4)]
     path = DECODE_PATHS["jnp-batch"]
     refs = [path.decode(f) for f in files]           # serial comparison
-    before = pipeline.TRANSFORM_BATCH_CALLS          # (increments 4x above)
-    with mksvc(paths=[path], num_workers=1, max_batch=4,
-               max_wait_ms=500.0, cache_bytes=0) as svc:
+    with trace.use_tracer(trace.Tracer()) as tracer, \
+            mksvc(paths=[path], num_workers=1, max_batch=4,
+                  max_wait_ms=500.0, cache_bytes=0) as svc:
         futs = [svc.submit(f) for f in files]
         for fut, ref in zip(futs, refs):
             np.testing.assert_array_equal(fut.result(timeout=60), ref)
-    assert pipeline.TRANSFORM_BATCH_CALLS == before + 1
+    launches = [e for e in tracer.events() if e["name"] == "jpeg.transform"]
+    assert len(launches) == 1 and launches[0]["args"] == {"batch": 4}
 
 
 def test_batch_level_failure_fails_futures_not_worker(corpus):
