@@ -3,8 +3,15 @@
 This stage is bit-serial *within* a restart segment (each symbol's
 position depends on the previous), so it runs on the host CPU —
 mirroring the paper's CPU-decode scope; the parallel transform stages
-(dequant/IDCT/color) are JAX/Pallas. Decode uses 16-bit-window LUTs
-(libjpeg-style) rather than per-bit walks.
+(dequant/IDCT/color) are JAX/Pallas. Baseline decode looks up a 16-bit
+window of the stream per symbol (libjpeg-style) rather than walking bits:
+for AC, one lookup in ``_ac_table`` yields the code's length, run and the
+coefficient itself whenever code and magnitude bits fit the window, and
+only the rare pairs longer than 16 bits read their magnitude bits apart
+(the slow path, counted as ``ac_slow``). The bit buffer is three local
+ints, and coefficients go straight into one flat buffer per component.
+Progressive decode keeps ``BitReader`` and the plain ``T.decode_lut``
+tables.
 
 Restart intervals (DRI/RSTn) break that serial chain: each segment is
 byte-aligned and starts with DC predictors at 0 (F.2.2.4), so per-segment
@@ -33,8 +40,10 @@ import multiprocessing
 import os
 import threading
 import time
+from array import array
 from concurrent.futures import ProcessPoolExecutor
 from functools import lru_cache
+from itertools import chain, repeat
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -186,7 +195,10 @@ STATS = EntropyStats()
 
 def entropy_stats() -> Dict[str, int]:
     """Process-wide counter snapshot: ``parallel_images``,
-    ``serial_images``, ``segments_parallel``, and ``fallback_*`` reasons."""
+    ``serial_images``, ``segments_parallel``, ``fallback_*`` reasons, and
+    over baseline images ``ac_symbols`` (nonzero AC coefficients decoded)
+    and ``ac_slow`` (those whose code and magnitude bits ran past the
+    16-bit window, so took the slow path)."""
     return STATS.snapshot()
 
 
@@ -248,6 +260,95 @@ def _luts_for(tables_key: tuple) -> dict:
             in tables_key}
 
 
+def _lut_runs(lut_sym: np.ndarray, lut_len: np.ndarray):
+    """(symbol, code length, windows) for each run of equal adjacent
+    windows of a ``T.decode_lut``, in window order: a few hundred runs,
+    where the 65536 windows would cost a numpy pass each."""
+    change = np.flatnonzero((lut_sym[1:] != lut_sym[:-1])
+                            | (lut_len[1:] != lut_len[:-1])) + 1
+    starts = np.concatenate(([0], change))
+    return zip(lut_sym[starts].tolist(), lut_len[starts].tolist(),
+               np.diff(starts, append=65536).tolist())
+
+
+def _expand(heads: list, counts: list) -> list:
+    """The 65536-entry table holding ``heads[i]`` ``counts[i]`` times:
+    its references share a few thousand objects."""
+    return list(chain.from_iterable(map(repeat, heads, counts)))
+
+
+def _dc_table(lut_sym: np.ndarray, lut_len: np.ndarray) -> list:
+    """DC window -> ``size << 5 | code length``, or -1 for no code."""
+    heads, counts = [], []
+    for sym, length, windows in _lut_runs(lut_sym, lut_len):
+        heads.append(sym << 5 | length if sym >= 0 else -1)
+        counts.append(windows)
+    return _expand(heads, counts)
+
+
+# kinds of an AC entry that decodes no coefficient in one step (nb == 0)
+_AC_BAD, _AC_EOB, _AC_ZRL, _AC_SLOW = -1, 0, 1, 0x100
+
+
+def _ac_table(lut_sym: np.ndarray, lut_len: np.ndarray) -> list:
+    """AC window -> ``(nb, run, value)``: code and magnitude decoded in
+    one lookup. Where code length + magnitude size fit the 16-bit window,
+    ``nb`` is the bits the pair takes, ``run`` the zeros before the
+    coefficient and ``value`` the coefficient. Otherwise ``nb`` is 0,
+    ``run`` the kind (``_AC_EOB``, ``_AC_ZRL``, ``_AC_BAD``, or
+    ``_AC_SLOW | rs`` when the magnitude bits run past the window) and
+    ``value`` the code length."""
+    heads, counts = [], []
+    for sym, length, windows in _lut_runs(lut_sym, lut_len):
+        size = sym & 15
+        if sym < 0:
+            heads.append((0, _AC_BAD, 0))
+        elif sym == 0:
+            heads.append((0, _AC_EOB, length))
+        elif sym == 0xF0:
+            heads.append((0, _AC_ZRL, length))
+        elif length + size > 16:
+            heads.append((0, _AC_SLOW | sym, length))
+        else:
+            # per code, the 2**size magnitudes in bit order (F.2.2.1's
+            # EXTEND), each over the windows its remaining bits leave
+            nb, run = length + size, sym >> 4
+            half = (1 << size) >> 1
+            code = [(nb, run, b if b >= half else b - (1 << size) + 1)
+                    for b in range(1 << size)]
+            n_codes = windows >> (16 - length)   # > 1 if a symbol repeats
+            heads += code * n_codes
+            counts += [1 << (16 - nb)] * (len(code) * n_codes)
+            continue
+        counts.append(windows)
+    return _expand(heads, counts)
+
+
+@lru_cache(maxsize=16)
+def _fast_tables_for(tables_key: tuple) -> dict:
+    """{(tc, th): list} of ``_dc_table`` / ``_ac_table`` entries for one
+    table set, about 2.3 MB. Cached apart from ``_luts_for``: progressive
+    decode reads that one per scan and never needs these."""
+    out = {}
+    for key, (bits, vals) in tables_key:
+        lut = T.decode_lut(bits, vals)
+        out[key] = _ac_table(*lut) if key[0] else _dc_table(*lut)
+    return out
+
+
+class _SlowTally(threading.local):
+    """AC symbols this thread decoded on the slow path, summed over its
+    ``decode_segment`` calls; callers read the difference around theirs."""
+    ac_slow = 0
+
+
+_TALLY = _SlowTally()
+_ZZ = T.ZIGZAG.tolist()
+# zero bytes past a segment's end: one 32-bit refill of lookahead, so a
+# refill that runs off the padded words has consumed past the real end
+_PAD = b"\x00" * 4
+
+
 def component_layout(spec: DecodeSpec) -> tuple:
     """The picklable component spec ``decode_segment`` takes:
     ((cid, h, v, td, ta), ...) in scan order."""
@@ -265,67 +366,124 @@ def decode_segment(seg: bytes, tables_key: tuple, components: tuple,
     natural-order coefficient blocks indexed by segment-relative MCU;
     the caller scatters them into the image's block grid by absolute MCU
     index. Raises ``CorruptJpeg`` on invalid codes, run overflow, or a
-    segment too short for its MCU count (truncation)."""
-    luts = _luts_for(tables_key)
-    br = BitReader(seg)
-    out = {cid: np.zeros((n_mcus, v, h, 64), dtype=np.int32)
-           for cid, h, v, _, _ in components}
-    preds = {cid: 0 for cid, _, _, _, _ in components}
-    inv_zz = T.ZIGZAG  # zigzag index i -> natural position
+    segment too short for its MCU count (truncation).
 
-    for m in range(n_mcus):
-        for cid, h, v, td, ta in components:
-            dc_sym, dc_len = luts[(0, td)]
-            ac_sym, ac_len = luts[(1, ta)]
-            grid = out[cid]
-            for dy in range(v):
-                for dx in range(h):
-                    blk = np.zeros(64, dtype=np.int32)
-                    w = br.peek16()
-                    s = int(dc_sym[w])
-                    if s < 0:
-                        raise CorruptJpeg("bad DC code")
-                    br.drop(int(dc_len[w]))
-                    diff = _extend(br.get(s), s)
-                    preds[cid] += diff
-                    blk[0] = preds[cid]
-                    k = 1
-                    while k < 64:
-                        w = br.peek16()
-                        rs = int(ac_sym[w])
-                        if rs < 0:
-                            raise CorruptJpeg("bad AC code")
-                        br.drop(int(ac_len[w]))
-                        if rs == 0:          # EOB
-                            break
-                        if rs == 0xF0:       # ZRL
-                            k += 16
-                            continue
-                        k += rs >> 4
-                        size = rs & 0xF
-                        if k > 63:
-                            raise CorruptJpeg("AC run overflow")
-                        blk[inv_zz[k]] = _extend(br.get(size), size)
+    One ``_ac_table`` lookup decodes an AC code with its magnitude. The
+    bit buffer lives in locals: the ``nbits`` unread bits are the bottom
+    of ``acc`` (consumed bits above them are masked off at 48 bits), and
+    a 32-bit word comes in whenever fewer than 16 are left. Coefficients
+    go straight into one flat buffer per component."""
+    tabs = _fast_tables_for(tables_key)
+    if not isinstance(seg, (bytes, bytearray)):
+        seg = bytes(seg)
+    data = seg.replace(b"\xff\x00", b"\xff")    # destuff
+    n = len(data)
+    words = np.frombuffer(data + b"\x00" * (-n % 4) + _PAD,
+                          dtype=">u4").tolist()
+    bufs, slots = {}, []
+    for ci, (cid, h, v, td, ta) in enumerate(components):
+        stride = 64 * h * v
+        buf = bufs[cid] = array("i", bytes(4 * stride * n_mcus))
+        for j in range(h * v):
+            slots.append((ci, tabs[(0, td)], tabs[(1, ta)], buf, 64 * j,
+                          stride))
+    preds = [0] * len(components)
+    zz = _ZZ
+    acc = nbits = wi = slow = k = m = 0
+    try:
+        for m in range(n_mcus):
+            for ci, dc, ac, buf, off, stride in slots:
+                base = m * stride + off
+                k = 1          # set before any refill: see the except
+                if nbits < 16:
+                    acc = ((acc << 32) | words[wi]) & 0xFFFFFFFFFFFF
+                    wi += 1
+                    nbits += 32
+                e = dc[(acc >> (nbits - 16)) & 0xFFFF]
+                if e < 0:
+                    raise CorruptJpeg("bad DC code")
+                nbits -= e & 31
+                s = e >> 5
+                if s:
+                    while nbits < s:
+                        acc = ((acc << 32) | words[wi]) & 0xFFFFFFFFFFFF
+                        wi += 1
+                        nbits += 32
+                    nbits -= s
+                    d = (acc >> nbits) & ((1 << s) - 1)
+                    if d < 1 << (s - 1):
+                        d -= (1 << s) - 1
+                    preds[ci] += d
+                buf[base] = preds[ci]
+                while k < 64:
+                    if nbits < 16:
+                        acc = ((acc << 32) | words[wi]) & 0xFFFFFFFFFFFF
+                        wi += 1
+                        nbits += 32
+                    nb, run, val = ac[(acc >> (nbits - 16)) & 0xFFFF]
+                    if nb:
+                        nbits -= nb
+                        k += run
+                        # zz[k] past 63 raises IndexError: a run overflow
+                        buf[base + zz[k]] = val
                         k += 1
-                    grid[m, dy, dx] = blk
-    if br.bits_consumed() > 8 * br.n:
+                    elif run == _AC_EOB:
+                        nbits -= val
+                        break
+                    elif run == _AC_ZRL:
+                        nbits -= val
+                        k += 16
+                    elif run == _AC_BAD:
+                        raise CorruptJpeg("bad AC code")
+                    else:      # magnitude bits past the window
+                        nbits -= val
+                        k += (run >> 4) & 15
+                        s = run & 15
+                        if nbits < s:
+                            acc = ((acc << 32) | words[wi]) & 0xFFFFFFFFFFFF
+                            wi += 1
+                            nbits += 32
+                        nbits -= s
+                        a = (acc >> nbits) & ((1 << s) - 1)
+                        if a < 1 << (s - 1):
+                            a -= (1 << s) - 1
+                        buf[base + zz[k]] = a
+                        k += 1
+                        slow += 1
+    except IndexError:
+        # from zz[k] with k > 63 (a run overflow), or from words[wi] once
+        # the lookahead padding is spent; a run past 63 is refused even
+        # where its magnitude bits would run off the end, as ever
+        if k > 63:
+            raise CorruptJpeg("AC run overflow") from None
+        raise CorruptJpeg(
+            f"truncated entropy segment: MCU {m} of {n_mcus} reads past "
+            f"the {8 * n} bits available") from None
+    _TALLY.ac_slow += slow
+    consumed = 32 * wi - nbits
+    if consumed > 8 * n:
         raise CorruptJpeg(
             f"truncated entropy segment: decoded {n_mcus} MCUs consumed "
-            f"{br.bits_consumed()} bits of {8 * br.n} available")
-    return out
+            f"{consumed} bits of {8 * n} available")
+    return {cid: np.frombuffer(bufs[cid], dtype=np.int32).reshape(
+                n_mcus, v, h, 64)
+            for cid, h, v, _, _ in components}
 
 
 def _decode_chunk(segs: List[bytes], counts: List[int], tables_key: tuple,
                   components: tuple) -> list:
     """Executor task: decode a contiguous run of segments. Returns
-    [(coefficients, t0, dur), ...] with CLOCK_MONOTONIC timestamps
-    (system-wide on Linux), so the parent emits ``jpeg.entropy.segment``
-    spans for work that happened in a worker process."""
+    [(coefficients, t0, dur, ac_slow), ...] with CLOCK_MONOTONIC
+    timestamps (system-wide on Linux), so the parent emits
+    ``jpeg.entropy.segment`` spans and counts slow-path symbols for work
+    that happened in a worker process."""
     out = []
     for seg, n_mcus in zip(segs, counts):
+        slow0 = _TALLY.ac_slow
         t0 = time.monotonic()
         coef = decode_segment(seg, tables_key, components, n_mcus)
-        out.append((coef, t0, time.monotonic() - t0))
+        out.append((coef, t0, time.monotonic() - t0,
+                    _TALLY.ac_slow - slow0))
     return out
 
 
@@ -400,7 +558,9 @@ def _resolve_mode(requested: int, n_segments: int) -> Tuple[str, str]:
 
 
 def _decode_serial(out, segs, counts, tables_key, components,
-                   mcu_cols) -> None:
+                   mcu_cols) -> int:
+    """-> AC symbols decoded on the slow path."""
+    slow0 = _TALLY.ac_slow
     m0 = 0
     multi = len(segs) > 1
     for seg, n_mcus in zip(segs, counts):
@@ -411,10 +571,12 @@ def _decode_serial(out, segs, counts, tables_key, components,
             coef = decode_segment(seg, tables_key, components, n_mcus)
         _scatter(out, coef, m0, n_mcus, mcu_cols, components)
         m0 += n_mcus
+    return _TALLY.ac_slow - slow0
 
 
 def _decode_parallel(out, segs, counts, tables_key, components, workers,
-                     mcu_cols) -> None:
+                     mcu_cols) -> int:
+    """-> AC symbols decoded on the slow path."""
     pool = _EXECUTOR.get(workers)
     bounds = _chunk_bounds(len(segs), min(workers, len(segs)))
     futs = []
@@ -426,12 +588,15 @@ def _decode_parallel(out, segs, counts, tables_key, components, workers,
     offsets = [0]
     for n in counts:
         offsets.append(offsets[-1] + n)
+    slow = 0
     for lo, fut in futs:
-        for k, (coef, t0, dur) in enumerate(fut.result()):
+        for k, (coef, t0, dur, ac_slow) in enumerate(fut.result()):
             trace.complete("jpeg.entropy.segment", t0, dur,
                            mcus=counts[lo + k], parallel=True)
             _scatter(out, coef, offsets[lo + k], counts[lo + k],
                      mcu_cols, components)
+            slow += ac_slow
+    return slow
 
 
 def decode_coefficients(spec: DecodeSpec,
@@ -468,8 +633,8 @@ def decode_coefficients(spec: DecodeSpec,
                workers=requested if mode == "parallel" else 1)
         if mode == "parallel":
             STATS.bump(parallel_images=1, segments_parallel=len(segs))
-            _decode_parallel(out, segs, counts, tables_key, components,
-                             requested, mcu_cols)
+            slow = _decode_parallel(out, segs, counts, tables_key,
+                                    components, requested, mcu_cols)
         else:
             bumps = {"serial_images": 1}
             if fallback:
@@ -480,8 +645,12 @@ def decode_coefficients(spec: DecodeSpec,
                               workers=requested)
                 bumps[fallback] = 1
             STATS.bump(**bumps)
-            _decode_serial(out, segs, counts, tables_key, components,
-                           mcu_cols)
+            slow = _decode_serial(out, segs, counts, tables_key,
+                                  components, mcu_cols)
+        # a decoded AC magnitude is never 0, so nonzero ACs count symbols
+        symbols = sum(int(np.count_nonzero(g[..., 1:])) for g in out.values())
+        sp.set(ac_symbols=symbols, ac_slow=slow)
+        STATS.bump(ac_symbols=symbols, ac_slow=slow)
     for c in spec.components:
         by, bx, _ = out[c.cid].shape
         out[c.cid] = out[c.cid].reshape(by, bx, 8, 8)

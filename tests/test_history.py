@@ -216,6 +216,10 @@ def test_injected_entropy_slowdown_is_attributed(tmp_path, monkeypatch):
     must blame that stage — not just report the cell got slower."""
     from repro.jpeg import huffman
     cell = "single/numpy-fast"
+    # a process's first sweep can start on cold thread pools and read
+    # several times slower; the baseline is the warm sweep after it
+    run_sweep("smoke", only=[cell], trace=True,
+              out_dir=str(tmp_path / "warm"))
     base = run_sweep("smoke", only=[cell], trace=True,
                      out_dir=str(tmp_path / "base"))
     store = HistoryStore(str(tmp_path / "history.jsonl"))

@@ -369,13 +369,18 @@ def test_service_decodes_progressive_and_skips_unsupported():
     on progressive-capable arms; an unsupported frame family flows
     through probe -> keyless batch -> skip machinery and fails its own
     future with a typed error while batch-mates are served."""
+    from repro.codecs import contrib, list_decoders
     from repro.service.engine import DecodeService, ServiceConfig
 
     prog = _prog(_img(seed=12))
     forged = _base(_img(seed=13)).replace(b"\xff\xc0", b"\xff\xc9", 1)
     want = DEC(prog)
+    # the built-in arms: a contrib backend (pillow, opencv) registered by
+    # an earlier test in the process would decode the forged SOF9 frame
+    arms = [s for s in list_decoders(context=ExecContext.SERVICE)
+            if s.name not in contrib.available()]
     cfg = ServiceConfig(num_workers=2, cache_bytes=0, seed=1)
-    with DecodeService(cfg) as svc:
+    with DecodeService(cfg, paths=arms) as svc:
         futs = [svc.submit(prog) for _ in range(4)]
         bad = svc.submit(forged)
         for f in futs:
