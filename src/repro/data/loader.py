@@ -33,6 +33,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.data import augment
+from repro.data.augment import RandomResizedCrop
 from repro.jpeg.parser import CorruptJpeg, UnsupportedJpeg
 from repro.obs import trace
 from repro.store.sampler import window_shuffle_order
@@ -65,6 +67,12 @@ class LoaderConfig:
                                       # the path's capabilities and this
                                       # loader's exec context (demotions
                                       # recorded in stats(); DESIGN.md §10)
+    augment: Optional[RandomResizedCrop] = None
+                                      # per-sample crop + flip: collate
+                                      # pads instead of centre-fitting and
+                                      # emits crop boxes, which
+                                      # ``augment.resample`` turns into
+                                      # target_hw images on the device
 
 
 class SkipLedger:
@@ -141,7 +149,10 @@ def _proc_work(i):
 
 
 class DataLoader:
-    """Iterable over batches: dict(image [B,H,W,3] u8, label [B] i32).
+    """Iterable over batches: dict(image [B,H,W,3] u8, label [B] i32);
+    with ``cfg.augment``, image is the padded batch and ``crop`` [B,5] i32
+    the boxes ``augment.resample`` turns into [B,*target_hw,3] on the
+    device (``repro.data.augment``).
 
     ``files`` is either the paper's in-memory ``Sequence[bytes]`` or any
     ``repro.store.ByteSource`` (e.g. a mmap-backed ``ShardSource``); a
@@ -500,28 +511,32 @@ class DataLoader:
             raise ValueError(cfg.mode)
 
         th, tw = cfg.target_hw
-        imgs, labs = [], []
+        idxs, imgs, labs = [], [], []
         for i, img in decoded:
             # the cursor counts consumed epoch positions, including skips —
             # otherwise restoring after a skip replays/shifts the epoch order
             self.cursor += 1
             if img is None:
                 continue
-            imgs.append(center_fit(img, th, tw))
+            idxs.append(i)
+            imgs.append(center_fit(img, th, tw) if cfg.augment is None
+                        else img)
             labs.append(self.labels[i])
             if len(imgs) == cfg.batch_size:
-                with trace.span("loader.collate", batch=len(imgs)):
-                    batch = {"image": np.stack(imgs),
-                             "label": np.asarray(labs, np.int32)}
-                yield batch
-                imgs, labs = [], []
+                yield self._collate(idxs, imgs, labs)
+                idxs, imgs, labs = [], [], []
         if imgs and not cfg.drop_remainder:
-            with trace.span("loader.collate", batch=len(imgs)):
-                batch = {"image": np.stack(imgs),
-                         "label": np.asarray(labs, np.int32)}
-            yield batch
+            yield self._collate(idxs, imgs, labs)
         self.epoch += 1
         self.cursor = 0
+
+    def _collate(self, idxs, imgs, labs) -> dict:
+        if self.cfg.augment is not None:
+            return augment.collate(self.cfg.augment, self.cfg.target_hw,
+                                   self.epoch, idxs, imgs, labs)
+        with trace.span("loader.collate", batch=len(imgs)):
+            return {"image": np.stack(imgs),
+                    "label": np.asarray(labs, np.int32)}
 
 
 def prefetch_to_device(iterator, size: int = 2):

@@ -6,16 +6,19 @@ Pallas interpret mode.
 """
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.jpeg import tables as T
 from repro.kernels.decode_batch import TILE_N as DB_TILE, decode_batch_pallas
 from repro.kernels.dequant_idct import TILE_N as DQ_TILE, dequant_idct_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.idct8x8 import TILE_N, idct8x8_pallas
+from repro.kernels.rrc_resample import rrc_resample_pallas
 from repro.kernels.ycbcr2rgb import LANES, TILE_R, ycbcr2rgb_pallas
 
 _IDCT64 = jnp.asarray(T.idct64_matrix().astype(np.float32))
@@ -64,6 +67,21 @@ def decode_batch(x, qidx, qtables) -> jax.Array:
     out = decode_batch_pallas(xp, qip, qtables, _IDCT64,
                               interpret=_interpret())
     return out[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("size",))
+def rrc_resample(images, crop, size=(224, 224)) -> jax.Array:
+    """[B, H_pad, W_pad, 3] uint8 images, each top-left in its padding, +
+    [B, 5] int32 crop boxes (top, left, height, width, flip) -> [B, *size,
+    3] uint8: each box resampled onto ``size`` with PIL's antialiased
+    bilinear filter, flipped left-right where ``flip`` is set. One Pallas
+    launch per batch; one program for every crop of a batch shape."""
+    planes = lax.bitcast_convert_type(images ^ jnp.uint8(128), jnp.int8)
+    out = rrc_resample_pallas(planes.transpose(0, 3, 1, 2),
+                              jnp.asarray(crop, jnp.int32),
+                              out_hw=tuple(size), interpret=_interpret())
+    return lax.bitcast_convert_type(out.transpose(0, 2, 3, 1),
+                                    jnp.uint8) ^ jnp.uint8(128)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,

@@ -28,6 +28,7 @@ from repro.kernels.dequant_idct import TILE_N as DQ_TILE
 from repro.kernels.dequant_idct import dequant_idct_pallas
 from repro.kernels.idct8x8 import TILE_N as IDCT_TILE
 from repro.kernels.idct8x8 import idct8x8_pallas
+from repro.kernels.rrc_resample import rrc_resample_pallas
 from repro.kernels.ycbcr2rgb import LANES, TILE_R, ycbcr2rgb_pallas
 
 # 4:2:0 micro-batch of 16 ImageNet-val-sized 375x500 images: 24x32 MCUs of
@@ -119,3 +120,14 @@ def test_transform_batch_compiles_for_420_microbatch(one_chip):
     mem = compiled.memory_analysis()
     # the program and its temporaries fit one 16 GB v5e with room to spare
     assert mem.temp_size_in_bytes < 1 << 30, mem
+
+
+def test_rrc_resample_compiles_for_a_training_batch(one_chip):
+    """128 images padded to 512x512 onto 224x224, one program for every
+    crop: the crop boxes are scalar-prefetched, never part of the shape."""
+    x = _sds((128, 3, 512, 512), jnp.int8, one_chip)
+    crop = _sds((128, 5), jnp.int32, one_chip)
+    compiled = rrc_resample_pallas.lower(x, crop, out_hw=(224, 224),
+                                         interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (128, 3, 224, 224)
